@@ -18,17 +18,10 @@ import json
 import time
 from typing import Callable, Optional
 
+from repro.launch.dryrun import dryrun_one
 from repro.utils import get_logger
 
 log = get_logger("hillclimb")
-
-
-def dryrun_one(*args, **kwargs):
-    """Deferred import: the dryrun stack needs a jax with sharding.AxisType;
-    keeping it lazy lets the live-market pairs (epochdrv) run everywhere."""
-    from repro.launch.dryrun import dryrun_one as _dryrun_one
-
-    return _dryrun_one(*args, **kwargs)
 
 
 def show(tag, rec):

@@ -111,8 +111,8 @@ def run_generator_baseline(
     driver: str = "fused",
 ) -> OFLState:
     """F-DAFL / DENSE: two-stage synth→distill with a fixed uniform ensemble.
-    On accelerator backends the fused driver donates the caller's server/gen
-    params — invalidated after epoch 0; copy first if reused."""
+    The fused driver donates the caller's server/gen params — invalidated
+    after epoch 0; copy first if reused."""
     objective = GEN_OBJECTIVES[method]
     n = len(client_applies)
     impl = cfg.ensemble_impl if driver == "fused" else "looped"

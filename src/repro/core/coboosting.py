@@ -200,10 +200,10 @@ def run_coboosting(
     the ``cfg.backend_for("loss")`` kernel path) or the legacy per-batch python
     loop (always pure jnp — the parity baseline).
 
-    NOTE: on accelerator backends the fused driver donates the caller's
-    ``server_params`` / ``gen_params`` (and derived state) to the epoch
-    program — they are invalidated after the first epoch; copy them first if
-    you need them again (e.g. for a legacy A/B run from the same init)."""
+    NOTE: the fused driver donates the caller's ``server_params`` /
+    ``gen_params`` (and derived state) to the epoch program — they are
+    invalidated after the first epoch; copy them first if you need them
+    again (e.g. for a legacy A/B run from the same init)."""
     n = len(client_applies)
     # fused driver: client forwards run through the grouped ClientBank
     # (cfg.ensemble_impl, O(#groups) trace) — the legacy driver always uses

@@ -52,8 +52,12 @@ def make_logits_all_stacked(apply_fn: Callable) -> Callable:
 
 
 def ensemble_logits(logits_all: jax.Array, w: jax.Array) -> jax.Array:
-    """A_w(x) = Σ_k w_k f_k(x). logits_all: (n, B, C); w: (n,)."""
-    return jnp.einsum("k,k...->...", w.astype(jnp.float32), logits_all.astype(jnp.float32))
+    """A_w(x) = Σ_k w_k f_k(x). logits_all: (n, B, C); w: (n,). Exact f32 on
+    every backend (TPU's default f32 matmul would take bf16 inputs)."""
+    return jnp.einsum(
+        "k,k...->...", w.astype(jnp.float32), logits_all.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def ensemble_accuracy(logits_all: jax.Array, w: jax.Array, labels: jax.Array) -> jax.Array:

@@ -22,8 +22,8 @@ Parity contract with the legacy loops (pinned by tests/test_buffer_epoch.py):
   * identical optimizer-step indexing — the server step counter advances
     only on valid (unmasked) scan iterations.
 
-Server/optimizer/buffer state is donated back to the program on accelerator
-backends (donation is a no-op on CPU, so we skip it there to avoid warnings).
+Server/optimizer/buffer state is donated back to the program on every
+backend, so the CPU tests alias buffers exactly as the chip does.
 
 The Eq. 4 / Eq. 6 losses inside these programs route through the
 differentiable fused Pallas kernels (:mod:`repro.kernels`) according to
@@ -74,13 +74,6 @@ def distill_schedule(epoch: int, capacity: int) -> Tuple[jax.Array, jax.Array]:
     order = np.zeros((capacity,), np.int32)
     order[:size] = (ptr - size + perm) % capacity
     return jnp.asarray(order), jnp.asarray(size, jnp.int32)
-
-
-def _jit_epoch(fn: Callable, donate: Tuple[int, ...]):
-    """jit with state donation where the backend supports it (not CPU)."""
-    if jax.default_backend() == "cpu":
-        return jax.jit(fn)
-    return jax.jit(fn, donate_argnums=donate)
 
 
 def _masked_update(valid, old, new):
@@ -282,7 +275,7 @@ def make_coboost_epoch(
             key, srv_steps, gloss, dmean,
         )
 
-    return _jit_epoch(epoch_step, donate=(0, 1, 2, 3, 4, 5)), gen_opt, srv_opt
+    return jax.jit(epoch_step, donate_argnums=(0, 1, 2, 3, 4, 5)), gen_opt, srv_opt
 
 
 def make_adi_epoch(
@@ -319,7 +312,7 @@ def make_adi_epoch(
         )
         return server_params, srv_opt_state, buf, key, srv_steps, dmean
 
-    return _jit_epoch(epoch_step, donate=(0, 1, 3)), srv_opt
+    return jax.jit(epoch_step, donate_argnums=(0, 1, 3)), srv_opt
 
 
 def make_feddf_epoch(logits_all_fn: Callable, server_apply: Callable, cfg: OFLConfig):
@@ -343,4 +336,4 @@ def make_feddf_epoch(logits_all_fn: Callable, server_apply: Callable, cfg: OFLCo
         (sp, st, _, step), losses = jax.lax.scan(body, init, order)
         return sp, st, key, step, jnp.mean(losses)
 
-    return _jit_epoch(epoch_step, donate=(0, 1)), srv_opt
+    return jax.jit(epoch_step, donate_argnums=(0, 1)), srv_opt

@@ -132,24 +132,28 @@ def market_eval_fn(
     default; "looped" is the unrolled parity baseline)."""
     logits_all_fn, client_params = make_ensemble(client_applies, client_params, impl=impl)
 
+    # client params are arguments, not closure constants: closed over, all K
+    # clients' weights would be baked into every compiled program (tens of
+    # MB per batch shape) and crowd everything else out of a size-capped
+    # persistent compile cache
     @jax.jit
-    def _ens_preds(w, xb):
-        la = logits_all_fn(client_params, xb)
+    def _ens_preds(cp, w, xb):
+        la = logits_all_fn(cp, xb)
         return jnp.argmax(ensemble_logits(la, w), axis=-1)
 
     @jax.jit
-    def _batch_preds(server_params, w, xb):
+    def _batch_preds(cp, server_params, w, xb):
         srv_pred = jnp.argmax(server_apply(server_params, xb), axis=-1)
-        return _ens_preds(w, xb), srv_pred
+        return _ens_preds(cp, w, xb), srv_pred
 
     def eval_fn(server_params, w) -> Dict[str, float]:
         ens_ok = srv_ok = 0
         for i in range(0, len(test_x), batch_size):
             xb = jnp.asarray(test_x[i : i + batch_size])
             if server_params is None:
-                ep = _ens_preds(w, xb)
+                ep = _ens_preds(client_params, w, xb)
             else:
-                ep, sp = _batch_preds(server_params, w, xb)
+                ep, sp = _batch_preds(client_params, server_params, w, xb)
                 srv_ok += int((np.asarray(sp) == test_y[i : i + batch_size]).sum())
             ens_ok += int((np.asarray(ep) == test_y[i : i + batch_size]).sum())
         out = {"ensemble_acc": ens_ok / len(test_x)}

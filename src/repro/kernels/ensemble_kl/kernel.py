@@ -138,7 +138,9 @@ def _bwd_kernel(
 
     gcl_ref[...] = (w[:, :, None] * g_ens[None]).astype(gcl_ref.dtype)
     gst_ref[...] = (gT * (q - p)).astype(gst_ref.dtype)
-    gw_ref[...] += jnp.sum(cl * g_ens[None], axis=(1, 2))[:, None]
+    # reduce one axis at a time with every value kept 2-D: Mosaic has no
+    # layout for the 1-D (K,) intermediate of a two-axis reduction
+    gw_ref[...] += jnp.sum(jnp.sum(cl * g_ens[None], axis=2), axis=1, keepdims=True)
 
 
 def ensemble_kl_bwd_pallas(

@@ -10,7 +10,12 @@ def ensemble_kl_ref(
 ) -> jax.Array:
     """client_logits: (K, B, V); student_logits: (B, V); w: (K,).
     Returns per-sample KL(softmax(A_w/T) ‖ softmax(s/T))·T², shape (B,)."""
-    t = jnp.einsum("k,kbv->bv", w.astype(jnp.float32), client_logits.astype(jnp.float32))
+    # HIGHEST: exact f32 on every backend (TPU's default f32 matmul takes
+    # bf16 inputs), the same arithmetic as the kernel's f32 combine
+    t = jnp.einsum(
+        "k,kbv->bv", w.astype(jnp.float32), client_logits.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     t = t / temperature
     s = student_logits.astype(jnp.float32) / temperature
     lt = jax.nn.log_softmax(t, axis=-1)

@@ -34,11 +34,8 @@ def _kernel(
     k_ref,
     v_ref,
     o_ref,
-    lse_ref,
-    m_ref,
-    l_ref,
-    acc_ref,
-    *,
+    *refs,  # [lse_ref,] m_ref, l_ref, acc_ref
+    return_lse: bool,
     causal: bool,
     window: int,
     softcap: float,
@@ -48,6 +45,8 @@ def _kernel(
     seq_k: int,
     scale: float,
 ):
+    lse_ref = refs[0] if return_lse else None
+    m_ref, l_ref, acc_ref = refs[-3:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -87,11 +86,11 @@ def _kernel(
     @pl.when(ki == num_kv_tiles - 1)
     def _final():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-        l, m = l_ref[...], m_ref[...]
-        # fully-masked rows (padding beyond an SWA tail) get a huge lse so a
-        # recompute backward's p = exp(s - lse) underflows to exactly zero
-        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 1e30)
-        lse_ref[...] = lse[:, 0][None, :]  # (block_q, 1) -> (1, block_q)
+        if return_lse:
+            l, m = l_ref[...], m_ref[...]
+            # fully-masked rows (padding beyond an SWA tail) get a huge lse so
+            # a recompute backward's p = exp(s - lse) underflows to exactly 0
+            lse_ref[0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 1e30)
 
 
 def _mask_and_p(qs, kb, lse, qi, ki, *, causal, window, softcap, block_q, block_kv, seq_k):
@@ -154,8 +153,8 @@ def _bwd_dq_kernel(
     kb = k_ref[0].astype(jnp.float32)  # (bkv, hd)
     vb = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)  # (bq, hd)
-    lse = lse_ref[0][:, None]  # (bq, 1)
-    delta = delta_ref[0][:, None]
+    lse = lse_ref[0]  # (bq, 1)
+    delta = delta_ref[0]
 
     p, dact = _mask_and_p(
         qs, kb, lse, qi, ki, causal=causal, window=window, softcap=softcap,
@@ -206,8 +205,8 @@ def _bwd_dkv_kernel(
     kb = k_ref[0].astype(jnp.float32)  # (bkv, hd)
     vb = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]
-    delta = delta_ref[0][:, None]
+    lse = lse_ref[0]  # (bq, 1)
+    delta = delta_ref[0]
 
     p, dact = _mask_and_p(
         qs, kb, lse, qi, ki, causal=causal, window=window, softcap=softcap,
@@ -276,8 +275,10 @@ def flash_attention_bwd_pallas(
     dof = dout.reshape(b, sqp, kh, g, hd).transpose(0, 2, 3, 1, 4).reshape(bhg, sqp, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(b * kh, skp, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * kh, skp, hd)
-    lsef = lse.reshape(b, sqp, kh, g).transpose(0, 2, 3, 1).reshape(bhg, sqp)
-    deltaf = delta.reshape(b, sqp, kh, g).transpose(0, 2, 3, 1).reshape(bhg, sqp)
+    # per-row statistics as (BHG, S, 1) columns: a (block_q, 1) block spans
+    # the full minor dim, which the TPU's (8, 128) tiling accepts
+    lsef = lse.reshape(b, sqp, kh, g).transpose(0, 2, 3, 1).reshape(bhg, sqp, 1)
+    deltaf = delta.reshape(b, sqp, kh, g).transpose(0, 2, 3, 1).reshape(bhg, sqp, 1)
 
     common = dict(
         causal=causal, window=window, softcap=softcap,
@@ -288,8 +289,8 @@ def flash_attention_bwd_pallas(
         pl.BlockSpec((1, block_kv, hd), lambda bh, i, j, g=g: (bh // g, j, 0)),
         pl.BlockSpec((1, block_kv, hd), lambda bh, i, j, g=g: (bh // g, j, 0)),
         pl.BlockSpec((1, block_q, hd), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
-        pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+        pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
     ]
 
     dq = pl.pallas_call(
@@ -308,8 +309,8 @@ def flash_attention_bwd_pallas(
         pl.BlockSpec((1, block_kv, hd), lambda bh, ki, qi, g=g: (bh // g, ki, 0)),
         pl.BlockSpec((1, block_kv, hd), lambda bh, ki, qi, g=g: (bh // g, ki, 0)),
         pl.BlockSpec((1, block_q, hd), lambda bh, ki, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi)),
-        pl.BlockSpec((1, block_q), lambda bh, ki, qi: (bh, qi)),
+        pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
     ]
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, num_q_tiles=nq, **common),
@@ -365,13 +366,20 @@ def flash_attention_pallas(
     nq, nk = sqp // block_q, skp // block_kv
 
     # fold (B, KH, G) into one grid dim; layout (BHG, S, hd)
-    qf = q.reshape(b, sqp, kh, g, hd).transpose(0, 2, 3, 1, 4).reshape(b * kh * g, sqp, hd)
+    bhg = b * kh * g
+    qf = q.reshape(b, sqp, kh, g, hd).transpose(0, 2, 3, 1, 4).reshape(bhg, sqp, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(b * kh, skp, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * kh, skp, hd)
 
-    out, lse = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bhg, sqp, hd), q.dtype)]
+    if return_lse:
+        out_specs.append(pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bhg, sqp, 1), jnp.float32))
+    res = pl.pallas_call(
         functools.partial(
             _kernel,
+            return_lse=return_lse,
             causal=causal,
             window=window,
             softcap=softcap,
@@ -381,20 +389,14 @@ def flash_attention_pallas(
             seq_k=sk,
             scale=scale,
         ),
-        grid=(b * kh * g, nq, nk),
+        grid=(bhg, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_kv, hd), lambda bh, qi, ki: (bh // g, ki, 0)),
             pl.BlockSpec((1, block_kv, hd), lambda bh, qi, ki: (bh // g, ki, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qi, ki: (bh, qi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * kh * g, sqp, hd), q.dtype),
-            jax.ShapeDtypeStruct((b * kh * g, sqp), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -402,8 +404,8 @@ def flash_attention_pallas(
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    out = out.reshape(b, kh, g, sqp, hd).transpose(0, 3, 1, 2, 4).reshape(b, sqp, h, hd)
+    out = res[0].reshape(b, kh, g, sqp, hd).transpose(0, 3, 1, 2, 4).reshape(b, sqp, h, hd)
     if not return_lse:
         return out[:, :sq]
-    lse = lse.reshape(b, kh, g, sqp).transpose(0, 3, 1, 2).reshape(b, sqp, h)
+    lse = res[1].reshape(b, kh, g, sqp).transpose(0, 3, 1, 2).reshape(b, sqp, h)
     return out[:, :sq], lse[:, :sq]

@@ -5,8 +5,11 @@ each grid step gathers ONE fixed-size KV page through the per-slot page table
 and folds it into VMEM-resident online-softmax statistics, so HBM traffic is
 the live pages only — never a dense ``(slots, max_len)`` rectangle.
 
-  * grid ``(B, KH, W)`` — pages minor, so the (m, l, acc) scratch carries one
-    row's statistics across its page sweep;
+  * grid ``(B, W)`` — pages minor, so the (m, l, acc) scratch carries one
+    row's statistics across its page sweep. Each step DMAs one whole page,
+    every KV head at once: a ``(1, ps, KH, hd)`` block spans the full
+    ``(KH, hd)`` minor dims, which is the only KV-head blocking the TPU's
+    (8, 128) tiling accepts when ``KH`` is not a multiple of 8;
   * the page table and per-row positions ride in as **scalar prefetch**
     (:class:`pltpu.PrefetchScalarGridSpec`): the K/V BlockSpec index maps read
     ``table[b, w]`` to DMA the right page — the gather happens in the
@@ -16,7 +19,9 @@ the live pages only — never a dense ``(slots, max_len)`` rectangle.
     ``attn_decode``), and fully-masked pages are skipped via ``@pl.when``;
   * GQA puts the ``q_per_kv`` query heads of one (row, kv-head) pair on the
     MXU tile's sublanes — tiny tiles (g ≤ 8 rows), which is the nature of
-    Sq=1 decode; batching across slots is the engine's job, not the grid's.
+    Sq=1 decode; the kernel body loops over the KV heads of the page (a
+    static unroll). Batching across slots is the engine's job, not the
+    grid's.
 
 Unallocated page-table entries point at the pool's scratch page — a valid
 page id whose reads are fully masked (it exists as a safe DMA/write target;
@@ -37,23 +42,24 @@ NEG = -1e30
 def _kernel(
     table_ref,  # scalar prefetch: (B, W) int32 page table
     pos_ref,  # scalar prefetch: (B,) int32 per-row positions
-    q_ref,  # (1, 1, G, hd)
-    k_ref,  # (1, ps, 1, hd) — the page picked by the index map
+    q_ref,  # (1, KH, G, hd)
+    k_ref,  # (1, ps, KH, hd) — the page picked by the index map
     v_ref,
-    o_ref,  # (1, 1, G, hd)
-    m_ref,  # VMEM (G, 1)
-    l_ref,  # VMEM (G, 1)
-    acc_ref,  # VMEM (G, hd)
+    o_ref,  # (1, KH, G, hd)
+    m_ref,  # VMEM (KH, G, 1)
+    l_ref,  # VMEM (KH, G, 1)
+    acc_ref,  # VMEM (KH, G, hd)
     *,
     window: int,
     softcap: float,
     page_size: int,
+    num_kv_heads: int,
     num_pages: int,
     cache_len: int,
     scale: float,
 ):
     b = pl.program_id(0)
-    wi = pl.program_id(2)
+    wi = pl.program_id(1)
 
     @pl.when(wi == 0)
     def _init():
@@ -72,15 +78,7 @@ def _kernel(
 
     @pl.when(page_live)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (ps, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G, ps)
-        if softcap > 0:
-            s = jnp.tanh(s / softcap) * softcap
-        j = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        j = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
         if window > 0:
             slot_w = p_b % cache_len
             wrap = (p_b // cache_len) * cache_len
@@ -88,22 +86,31 @@ def _kernel(
             ok = (k_pos >= 0) & (k_pos <= p_b) & (k_pos > p_b - window)
         else:
             ok = j <= p_b
-        ok &= j < cache_len
-        s = jnp.where(ok, s, NEG)
+        ok &= j < cache_len  # (1, ps), shared by every head of the page
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (G, hd)
+            k = k_ref[0, :, h].astype(jnp.float32)  # (ps, hd)
+            v = v_ref[0, :, h].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # (G, ps)
+            if softcap > 0:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(ok, s, NEG)
 
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        p_exp = jnp.exp(s - m_new)
-        corr = jnp.exp(m_old - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p_exp, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p_exp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            p_exp = jnp.exp(s - m_new)
+            corr = jnp.exp(m_old - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p_exp, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p_exp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            m_ref[h] = m_new
 
     @pl.when(wi == num_pages - 1)
     def _final():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_pallas(
@@ -129,17 +136,17 @@ def flash_decode_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, w),
+        grid=(b, w),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda bi, ki, wi, tbl, psc: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda bi, ki, wi, tbl, psc: (tbl[bi, wi], 0, ki, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda bi, ki, wi, tbl, psc: (tbl[bi, wi], 0, ki, 0)),
+            pl.BlockSpec((1, kh, g, hd), lambda bi, wi, tbl, psc: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, ps, kh, hd), lambda bi, wi, tbl, psc: (tbl[bi, wi], 0, 0, 0)),
+            pl.BlockSpec((1, ps, kh, hd), lambda bi, wi, tbl, psc: (tbl[bi, wi], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, ki, wi, tbl, psc: (bi, ki, 0, 0)),
+        out_specs=pl.BlockSpec((1, kh, g, hd), lambda bi, wi, tbl, psc: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -148,6 +155,7 @@ def flash_decode_pallas(
             window=window,
             softcap=softcap,
             page_size=ps,
+            num_kv_heads=kh,
             num_pages=w,
             cache_len=cl,
             scale=1.0 / float(hd) ** 0.5,

@@ -137,7 +137,9 @@ def _bwd_kernel(
     g_t = (g * coeff) * (p - onehot)
     g_t = jnp.where(valid, g_t, 0.0)
     gcl_ref[...] = (w[:, :, None] * g_t[None]).astype(gcl_ref.dtype)
-    gw_ref[...] += jnp.sum(cl * g_t[None], axis=(1, 2))[:, None]
+    # reduce one axis at a time with every value kept 2-D: Mosaic has no
+    # layout for the 1-D (K,) intermediate of a two-axis reduction
+    gw_ref[...] += jnp.sum(jnp.sum(cl * g_t[None], axis=2), axis=1, keepdims=True)
 
 
 def ghm_ce_bwd_pallas(

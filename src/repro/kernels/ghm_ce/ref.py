@@ -15,7 +15,12 @@ def ghm_ce_ref(
     """client_logits: (K, B, V); labels: (B,); w: (K,). Per-sample d·CE.
     ``stop_difficulty_grad`` treats d(x) as a constant under autodiff (the
     Eq. 6 generator-loss convention, matching ``ghs_loss``)."""
-    t = jnp.einsum("k,kbv->bv", w.astype(jnp.float32), client_logits.astype(jnp.float32))
+    # HIGHEST: exact f32 on every backend (TPU's default f32 matmul takes
+    # bf16 inputs), the same arithmetic as the kernel's f32 combine
+    t = jnp.einsum(
+        "k,kbv->bv", w.astype(jnp.float32), client_logits.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     lse = jax.scipy.special.logsumexp(t, axis=-1)
     ly = jnp.take_along_axis(t, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
     nll = lse - ly

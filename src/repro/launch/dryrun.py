@@ -48,9 +48,7 @@ def _parse_value(v: str):
 def _custom_mesh(spec: str):
     dims = tuple(int(d) for d in spec.split("x"))
     axes = {2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
-    from repro.launch.mesh import compat_make_mesh
-
-    return compat_make_mesh(dims, axes)
+    return jax.make_mesh(dims, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dryrun_one(

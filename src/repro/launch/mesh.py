@@ -10,27 +10,12 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older pinned jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def compat_mesh(devices, axes):
-    """A :class:`Mesh` over an EXPLICIT device array (submesh construction;
-    ``jax.make_mesh`` always grabs every device)."""
-    if AxisType is None:
-        return Mesh(devices, axes)
-    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
+def _auto(axes):
+    """Auto axis types: ``jax.make_mesh`` would default to Explicit."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,12 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     boundary)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh():
     """A 1×1 mesh over the single real device (tests / examples)."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(("data", "model")))
 
 
 def make_fleet_mesh(replicas: int, *, devices=None):
@@ -64,7 +49,9 @@ def make_fleet_mesh(replicas: int, *, devices=None):
             "a ragged split would strand devices. Pick a replica count that "
             f"divides {n}."
         )
-    return compat_mesh(devs.reshape(replicas, 1, n // replicas), ("replica", "data", "model"))
+    # an explicit device array: jax.make_mesh always takes every device
+    axes = ("replica", "data", "model")
+    return Mesh(devs.reshape(replicas, 1, n // replicas), axes, axis_types=_auto(axes))
 
 
 def replica_meshes(fleet_mesh):
@@ -73,7 +60,8 @@ def replica_meshes(fleet_mesh):
     are disjoint by construction: replica i's engine CANNOT address replica
     j's devices, which is what makes per-replica pool isolation physical."""
     devs = fleet_mesh.devices  # (replica, data, model)
-    return [compat_mesh(devs[i], ("data", "model")) for i in range(devs.shape[0])]
+    axes = ("data", "model")
+    return [Mesh(devs[i], axes, axis_types=_auto(axes)) for i in range(devs.shape[0])]
 
 
 def disagg_submeshes(mesh):
@@ -89,15 +77,7 @@ def disagg_submeshes(mesh):
     if m < 2:
         return mesh, mesh
     half = m // 2
-    prefill = compat_mesh(devs[..., :half], mesh.axis_names)
-    decode = compat_mesh(devs[..., half:], mesh.axis_names)
+    axes = mesh.axis_names
+    prefill = Mesh(devs[..., :half], axes, axis_types=_auto(axes))
+    decode = Mesh(devs[..., half:], axes, axis_types=_auto(axes))
     return prefill, decode
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh`` where the API exists (jax >= 0.5), else the Mesh's
-    own context manager (jax<0.5 pins in this container) — same effect for
-    the launch drivers: sharding constraints resolve against ``mesh``."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

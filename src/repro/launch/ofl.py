@@ -33,6 +33,7 @@ from repro.fed import build_market, build_market_grouped, market_eval_fn
 from repro.kernels import KERNEL_BACKENDS, policy_from_flags
 from repro.models.cnn import cnn_apply, init_cnn
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("ofl")
 
@@ -156,6 +157,7 @@ def main() -> None:
                         "(the fused epoch's jax.named_scope phases show up "
                         "in the device timeline)")
     args = p.parse_args()
+    enable_compile_cache()
     obs.configure(
         metrics=bool(args.metrics_out),
         trace=bool(args.trace_out),

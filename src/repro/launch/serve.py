@@ -41,7 +41,6 @@ from repro.launch.mesh import (
     make_fleet_mesh,
     make_host_mesh,
     make_production_mesh,
-    mesh_context,
     replica_meshes,
 )
 from repro.models import group_pattern, init_lm
@@ -58,6 +57,7 @@ from repro.serve import (
 )
 from repro.kernels import policy_from_flags
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("serve")
 
@@ -453,6 +453,7 @@ def main() -> None:
         # model's actual cache length (a reduced variant clamps the window)
         cfg = reduced_variant(cfg).replace(dtype="float32", param_dtype="float32")
     validate_args(args, cfg)  # before any device/mesh work
+    enable_compile_cache()
     obs.configure(
         metrics=bool(args.metrics_out),
         trace=bool(args.trace_out),
@@ -474,7 +475,7 @@ def main() -> None:
         run_continuous(args, cfg, params)
         return
     mesh = {"host": make_host_mesh, "production": make_production_mesh}[args.mesh]()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = init_lm(cfg, jax.random.key(args.seed))
         if args.engine == "static":
             run_static(args, cfg, params)

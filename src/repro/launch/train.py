@@ -24,6 +24,7 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import init_lm
 from repro.runtime import make_train_step
 from repro.utils import get_logger, tree_size
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("train")
 
@@ -42,6 +43,7 @@ def main() -> None:
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -172,26 +172,17 @@ def _jsonable(v):
 # -- jax profiler bridge -----------------------------------------------------
 
 
-def start_jax_profile(tracer: SpanTracer, profile_dir: str) -> bool:
+def start_jax_profile(tracer: SpanTracer, profile_dir: str) -> None:
     """Start a JAX profiler trace into ``profile_dir`` and bridge every span
-    to a TraceAnnotation so host spans land in the device timeline too.
-    Returns False (and leaves the tracer untouched) when the installed jax
-    has no profiler support."""
-    try:
-        import jax
+    to a TraceAnnotation so host spans land in the device timeline too."""
+    import jax
 
-        jax.profiler.start_trace(profile_dir)
-    except Exception:  # pragma: no cover - depends on jax build
-        return False
+    jax.profiler.start_trace(profile_dir)
     tracer.jax_bridge = True
-    return True
 
 
 def stop_jax_profile(tracer: SpanTracer) -> None:
-    tracer.jax_bridge = False
-    try:
-        import jax
+    import jax
 
-        jax.profiler.stop_trace()
-    except Exception:  # pragma: no cover - stop without start, old jax
-        pass
+    tracer.jax_bridge = False
+    jax.profiler.stop_trace()

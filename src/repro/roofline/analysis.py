@@ -122,8 +122,6 @@ def roofline_report(
     from repro.roofline.hlo_cost import HloCost
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     text = hlo_text if hlo_text is not None else compiled.as_text()
     walk = HloCost(text).totals()
     flops_dev = float(walk["flops"])

@@ -443,8 +443,7 @@ class DecodeWorker:
         self._splice_jit = jax.jit(self._splice_fn)
         self._cow_jit = jax.jit(self._cow_fn)
         self._stale_slots: set = set()
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=donate)
+        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=(1,))
         self.reset()
 
     # -- device programs ----------------------------------------------------

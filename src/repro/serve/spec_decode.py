@@ -86,8 +86,7 @@ class SpecDecoder:
         self._proposed = jnp.zeros((), jnp.int32)
         self._accepted = jnp.zeros((), jnp.int32)
         self._nsteps = jnp.zeros((), jnp.int32)
-        donate = () if jax.default_backend() == "cpu" else (2, 3)
-        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=donate)
+        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=(2, 3))
         self._prefill_jit = jax.jit(self._prefill_fn)
 
     # -- device programs ----------------------------------------------------

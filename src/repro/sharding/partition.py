@@ -87,13 +87,8 @@ LOGICAL_RULES: List[Tuple[str, List[LogicalSpec]]] = [
 
 
 def _mesh_axes() -> Dict[str, int]:
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-    else:  # jax < 0.5: only the thread-local physical mesh context exists
-        from jax._src import mesh as mesh_lib
-
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return {}
     return dict(mesh.shape)
 

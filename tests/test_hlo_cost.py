@@ -75,10 +75,7 @@ def test_matches_compiled_scan_exactly():
     t = HloCost(compiled.as_text()).totals()
     assert t["flops"] == pytest.approx(7 * 2 * 64**3, rel=0.01)
     # raw cost_analysis counts ONE iteration — the caveat this walker fixes
-    raw = compiled.cost_analysis()
-    if isinstance(raw, list):  # older jax returns [dict]
-        raw = raw[0]
-    raw = raw["flops"]
+    raw = compiled.cost_analysis()["flops"]
     assert raw == pytest.approx(2 * 64**3, rel=0.01)
 
 

@@ -1,0 +1,153 @@
+"""Compile every main-path Pallas kernel for a TPU v5e, without a chip.
+
+Interpret mode runs a kernel body as plain JAX, so it cannot show what the
+TPU compiler (Mosaic) refuses: blocks that break the (8, 128) tiling, values
+it has no layout for, more VMEM than a kernel may use. These tests describe
+a ``v5e:2x2`` topology, lower each kernel for one of its chips at real
+widths, and compile it with the installed TPU compiler. Nothing runs; a
+pass says the kernel compiles, not that it is right (the interpret-mode
+parity tests say that).
+
+Shape sets: the paper's image-scale OFL epoch (K=10 clients, b=128, 10
+classes) and smollm-135m (9 query heads, 3 KV heads, head_dim 64, vocab
+49152, 2048-token sequences, 16-token KV pages).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and pytest-xdist
+workers all import this file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ensemble_kl.kernel import ensemble_kl_bwd_pallas, ensemble_kl_pallas
+from repro.kernels.flash_attention.kernel import (
+    flash_attention_bwd_pallas,
+    flash_attention_pallas,
+)
+from repro.kernels.flash_decode.kernel import flash_decode_pallas
+from repro.kernels.ghm_ce.kernel import ghm_ce_bwd_pallas, ghm_ce_pallas
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+# (K clients, rows, vocab, logit dtype)
+LOSS_SHAPES = {
+    "ofl-image": (10, 128, 10, F32),
+    "smollm-135m": (4, 256, 49152, BF16),
+}
+# smollm-135m attention: (batch, seq, heads, kv_heads, head_dim)
+ATTN_SHAPES = {
+    "prefill-256": (8, 256, 9, 3, 64),
+    "train-2048": (1, 2048, 9, 3, 64),
+}
+# smollm-135m paged decode: (slots, pages per slot, window, softcap)
+DECODE_SHAPES = {
+    "full": (8, 128, 0, 0.0),
+    "window-softcap": (8, 32, 256, 30.0),
+}
+DECODE_HEADS, DECODE_KV_HEADS, HEAD_DIM, PAGE = 9, 3, 64, 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _kernels(text: str) -> int:
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("shape", list(LOSS_SHAPES), ids=list(LOSS_SHAPES))
+def test_ensemble_kl_compiles(one_chip, shape):
+    k, b, v, dt = LOSS_SHAPES[shape]
+    S = lambda s, d=F32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    fwd = _compile(
+        lambda c, s, w: ensemble_kl_pallas(c, s, w, 4.0, return_stats=True),
+        S((k, b, v), dt), S((b, v), dt), S((k,)),
+    )
+    assert _kernels(fwd) >= 1
+    bwd = _compile(
+        lambda c, s, w, g, o, lt, ls: ensemble_kl_bwd_pallas(c, s, w, g, o, lt, ls, 4.0),
+        S((k, b, v), dt), S((b, v), dt), S((k,)), S((b,)), S((b,)), S((b,)), S((b,)),
+    )
+    assert _kernels(bwd) >= 1
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["weighted", "plain"])
+@pytest.mark.parametrize("shape", list(LOSS_SHAPES), ids=list(LOSS_SHAPES))
+def test_ghm_ce_compiles(one_chip, shape, weighted):
+    k, b, v, dt = LOSS_SHAPES[shape]
+    S = lambda s, d=F32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    fwd = _compile(
+        lambda c, y, w: ghm_ce_pallas(c, y, w, weighted=weighted, return_stats=True),
+        S((k, b, v), dt), S((b,), I32), S((k,)),
+    )
+    assert _kernels(fwd) >= 1
+    bwd = _compile(
+        lambda c, y, w, g, lse, ly: ghm_ce_bwd_pallas(c, y, w, g, lse, ly, weighted=weighted),
+        S((k, b, v), dt), S((b,), I32), S((k,)), S((b,)), S((b,)), S((b,)),
+    )
+    assert _kernels(bwd) >= 1
+
+
+@pytest.mark.parametrize("shape", list(ATTN_SHAPES), ids=list(ATTN_SHAPES))
+def test_flash_attention_compiles(one_chip, shape):
+    b, s, h, kh, hd = ATTN_SHAPES[shape]
+    S = lambda sh, d=BF16: jax.ShapeDtypeStruct(sh, d, sharding=one_chip)
+    q, kv = S((b, s, h, hd)), S((b, s, kh, hd))
+    assert _kernels(_compile(flash_attention_pallas, q, kv, kv)) >= 1
+    fwd = _compile(lambda q, k, v: flash_attention_pallas(q, k, v, return_lse=True), q, kv, kv)
+    assert _kernels(fwd) >= 1
+    # the backward is two kernels: dq (kv minor) and dk/dv (q minor)
+    bwd = _compile(flash_attention_bwd_pallas, q, kv, kv, q, S((b, s, h), F32), q)
+    assert _kernels(bwd) >= 2
+
+
+@pytest.mark.parametrize("shape", list(DECODE_SHAPES), ids=list(DECODE_SHAPES))
+def test_flash_decode_compiles(one_chip, shape):
+    b, w, window, softcap = DECODE_SHAPES[shape]
+    pages = b * w + 1
+    S = lambda sh, d=BF16: jax.ShapeDtypeStruct(sh, d, sharding=one_chip)
+    kv = S((pages, PAGE, DECODE_KV_HEADS, HEAD_DIM))
+    text = _compile(
+        lambda q, k, v, t, p: flash_decode_pallas(
+            q, k, v, t, p, window=window, softcap=softcap,
+            cache_len=min(window, w * PAGE) if window else 0,
+        ),
+        S((b, DECODE_HEADS, HEAD_DIM)), kv, kv, S((b, w), I32), S((b,), I32),
+    )
+    assert _kernels(text) >= 1
