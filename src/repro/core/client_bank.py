@@ -40,7 +40,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ensemble import ENSEMBLE_DTYPE, make_logits_all
+from repro.utils.scope import scoped
 from repro.utils.trees import tree_stack, tree_unstack
+
+#: The named scope of the client ensemble's forward (and, through autodiff,
+#: its backward) wherever a program calls it.
+BANK_SCOPE = "ofl.bank"
 
 
 def _apply_key(fn: Callable) -> Any:
@@ -175,13 +180,18 @@ class ClientBank:
     def logits_all(self, bank_params: Tuple[Any, ...], x: jax.Array) -> jax.Array:
         """f(bank_params, x) -> (K, B, C) stacked client logits in ORIGINAL
         client order — the drop-in replacement for the fn built by
-        :func:`repro.core.ensemble.make_logits_all`."""
-        outs = [self._group_logits(g, sp, x) for g, sp in enumerate(bank_params)]
-        stacked = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-        if self.is_client_ordered:
-            return stacked
-        inv = np.argsort(np.asarray(self.order))
-        return jnp.take(stacked, jnp.asarray(inv), axis=0)
+        :func:`repro.core.ensemble.make_logits_all`. Its ops carry the named
+        scope ``ofl.bank``, each group's ``ofl.bank.g<i>`` inside it."""
+        with jax.named_scope(BANK_SCOPE):
+            outs = []
+            for g, sp in enumerate(bank_params):
+                with jax.named_scope(f"{BANK_SCOPE}.g{g}"):
+                    outs.append(self._group_logits(g, sp, x))
+            stacked = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+            if self.is_client_ordered:
+                return stacked
+            inv = np.argsort(np.asarray(self.order))
+            return jnp.take(stacked, jnp.asarray(inv), axis=0)
 
     # -- interop ------------------------------------------------------------
 
@@ -234,9 +244,11 @@ def make_ensemble(
     * ``impl="looped"``  — the original python-unrolled per-client loop over
       a tuple of param trees (the parity baseline and the legacy driver's
       path).
+
+    Either way the callable runs under the named scope ``ofl.bank``.
     """
     if impl == "looped":
-        return make_logits_all(list(apply_fns)), tuple(params_list)
+        return scoped(BANK_SCOPE, make_logits_all(list(apply_fns))), tuple(params_list)
     if impl != "grouped":
         raise ValueError(f"unknown ensemble impl {impl!r}; expected one of {ENSEMBLE_IMPLS}")
     bank, bank_params = ClientBank.build(

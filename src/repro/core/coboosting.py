@@ -179,6 +179,15 @@ def make_ee_step(logits_all_fn: Callable, cfg: OFLConfig, num_clients: int):
     return step
 
 
+def _completed(result: jax.Array, since: float) -> float:
+    """Wait for an epoch's ``result``; observe its ``ofl.epoch.step_s`` from
+    ``since`` (the previous completion) and return the completion time."""
+    result.block_until_ready()
+    now = time.perf_counter()
+    obs.observe("ofl.epoch.step_s", now - since, driver="fused")
+    return now
+
+
 def run_coboosting(
     client_applies: List[Callable],
     client_params: List[Any],
@@ -223,14 +232,18 @@ def run_coboosting(
         buf = init_synth_buffer(gen_apply, gen_params, cfg)
         state = OFLState(server_params, gen_params, w, [], [], [])
         srv_steps = jnp.zeros((), jnp.int32)
+        # ofl.epoch.step_s times execution, one epoch deep: with the registry
+        # on, each epoch is dispatched before the previous one is waited for,
+        # and the sample is the interval between their completions (the
+        # first from the loop's start, so it holds the compile). With the
+        # registry off nothing here blocks.
+        timed = obs.registry().enabled
+        pending, t_done = None, time.perf_counter()
         for epoch in range(cfg.epochs):
             slot_order, n_valid = distill_schedule(epoch, cfg.buffer_batches)
-            # the span/timer bracket the DISPATCH of the fused program — no
-            # sync is forced, so in steady state dispatch time backpressures
-            # to epoch time once the device pipeline fills. Per-phase device
-            # time comes from jax.named_scope inside the program (visible
-            # under --profile-dir), not from host stamps.
-            t0 = time.perf_counter()
+            # the span brackets the DISPATCH of the fused program. Per-phase
+            # and per-network device time comes from the jax.named_scopes
+            # inside the program (visible under --profile-dir).
             with obs.span("ofl.epoch", epoch=epoch, driver="fused"):
                 (
                     state.server_params, srv_opt_state, state.gen_params, gen_opt_state,
@@ -240,7 +253,10 @@ def run_coboosting(
                     state.weights, buf, key, srv_steps, slot_order, n_valid, client_params,
                 )
             state.dispatch_count += 1
-            obs.observe("ofl.epoch.step_s", time.perf_counter() - t0, driver="fused")
+            if timed:
+                if pending is not None:
+                    t_done = _completed(pending, t_done)
+                pending = dmean
             obs.inc("ofl.epoch.count")
             obs.inc("ofl.epoch.dispatches")
             obs.inc("ofl.gen.steps", cfg.gen_iters)
@@ -248,6 +264,9 @@ def run_coboosting(
                 obs.inc("ofl.ee.steps")
             obs.inc("ofl.kd.steps", int(n_valid))
             if eval_fn is not None and ((epoch + 1) % eval_every == 0 or epoch == cfg.epochs - 1):
+                if timed:  # the evaluation waits for this epoch anyway
+                    _completed(pending, t_done)
+                    pending = None
                 metrics = eval_fn(state.server_params, state.weights)
                 metrics.update(epoch=epoch, gen_loss=float(gloss), distill_loss=float(dmean))
                 state.history.append(metrics)
@@ -256,6 +275,9 @@ def run_coboosting(
                     epoch, float(gloss), float(dmean),
                     {k: round(v, 4) for k, v in metrics.items() if isinstance(v, float)},
                 )
+                t_done = time.perf_counter()  # the next epoch starts after it
+        if pending is not None:
+            _completed(pending, t_done)
         state.buffer = buf
         state.buffer_x, state.buffer_y = buffer_as_lists(buf)
         return state
@@ -277,12 +299,10 @@ def run_coboosting(
         key, k1, k2, k3 = jax.random.split(key, 4)
         # 1. generator phase (lines 5–9)
         z, y = _sample_zy(k1, cfg.batch_size, cfg.latent_dim, num_classes)
-        t0 = time.perf_counter()
         with obs.span("ofl.gen.boost", epoch=epoch, iters=cfg.gen_iters):
             state.gen_params, gen_opt_state, gloss = gen_phase(
                 state.gen_params, gen_opt_state, z, y, client_params, state.weights, state.server_params
             )
-        obs.observe("ofl.gen.step_s", time.perf_counter() - t0)
         obs.inc("ofl.gen.steps", cfg.gen_iters)
         obs.inc("ofl.epoch.dispatches")
         x_new = gen_apply(state.gen_params, z, y)
@@ -294,10 +314,8 @@ def run_coboosting(
 
         # 2–3. EE on the (diversified) fresh hard batch (lines 11–14)
         if cfg.use_ee:
-            t0 = time.perf_counter()
             with obs.span("ofl.ee.weight_search", epoch=epoch):
                 state.weights = ee_step(state.weights, x_new, y, k2, client_params)
-            obs.observe("ofl.ee.step_s", time.perf_counter() - t0)
             obs.inc("ofl.ee.steps")
             obs.inc("ofl.epoch.dispatches")
 
@@ -306,7 +324,6 @@ def run_coboosting(
         with obs.span("ofl.kd", epoch=epoch, batches=len(state.buffer_x)):
             for bi in np.random.RandomState(epoch).permutation(len(state.buffer_x)):
                 k3, kb = jax.random.split(k3)
-                t0 = time.perf_counter()
                 state.server_params, srv_opt_state, dl = distill_step(
                     state.server_params,
                     srv_opt_state,
@@ -316,7 +333,6 @@ def run_coboosting(
                     state.weights,
                     jnp.asarray(srv_step_idx, jnp.int32),
                 )
-                obs.observe("ofl.kd.step_s", time.perf_counter() - t0)
                 obs.inc("ofl.kd.steps")
                 obs.inc("ofl.epoch.dispatches")
                 srv_step_idx += 1

@@ -56,6 +56,7 @@ from repro.kernels import ensemble_kl, ghm_ce
 from repro.kernels.dispatch import resolve
 from repro.optim import adam, constant_schedule, sgdm
 from repro.optim.optimizers import apply_updates
+from repro.utils.scope import scoped
 
 
 def distill_schedule(epoch: int, capacity: int) -> Tuple[jax.Array, jax.Array]:
@@ -91,8 +92,10 @@ def make_kd_loss(
     ``kernel_backend`` (resolved once, at make time) routes the loss through
     the differentiable fused :func:`repro.kernels.ensemble_kl` kernel — the
     Pallas paths never materialize A_w in the forward pass — or through the
-    legacy jnp composition (``"ref"``; the auto choice off-TPU)."""
+    legacy jnp composition (``"ref"``; the auto choice off-TPU). The server
+    runs under the named scope ``ofl.server``."""
     backend = resolve("loss", kernel_backend)
+    server_apply = scoped("ofl.server", server_apply)
 
     if backend == "ref":
 
@@ -187,6 +190,10 @@ def make_coboost_epoch(
         -> (server_params, srv_opt_state, gen_params, gen_opt_state, w, buf,
             key', srv_steps, gloss, dmean)
     """
+    # the networks' named scopes (the KD sweep's make_kd_loss names the
+    # server there; the client bank names itself, DHS its own)
+    server_net = scoped("ofl.server", server_apply)
+    gen_apply = scoped("ofl.gen.net", gen_apply)
     gen_opt = adam(constant_schedule(cfg.gen_lr))
     srv_opt = sgdm(constant_schedule(cfg.server_lr), momentum=0.9)
     use_ee = cfg.use_ee if use_ee is None else use_ee
@@ -204,7 +211,7 @@ def make_coboost_epoch(
         if gen_objective is not None:
             return gen_objective(ensemble_logits(la, w), y, x)
         if backend == "ref":
-            s_logits = server_apply(server_params, x)
+            s_logits = server_net(server_params, x)
             return generator_loss(
                 ensemble_logits(la, w),
                 s_logits,
@@ -221,7 +228,7 @@ def make_coboost_epoch(
             ghm_ce(la, y, w, weighted=cfg.use_ghs, backend=backend, stop_difficulty_grad=True)
         )
         if cfg.use_adv:
-            s_logits = server_apply(server_params, x)
+            s_logits = server_net(server_params, x)
             loss = loss - cfg.beta * jnp.mean(
                 ensemble_kl(la, s_logits, w, temperature=cfg.gen_kl_temperature, backend=backend)
             )
@@ -238,7 +245,9 @@ def make_coboost_epoch(
 
         # jax.named_scope annotates the XLA ops of each Algorithm-1 phase —
         # zero host cost, but an --profile-dir device trace shows the phases
-        # as named regions lining up with the host-side ofl.epoch span.
+        # as named regions lining up with the host-side ofl.epoch span. The
+        # networks' scopes (ofl.bank, ofl.gen.net, ofl.server, ofl.dhs) nest
+        # inside them.
         # 1. generator phase (Algorithm 1 lines 5-9)
         with jax.named_scope("ofl.gen.boost"):
             z, y = _sample_zy(k1, cfg.batch_size, cfg.latent_dim, num_classes)

@@ -29,7 +29,9 @@ def diversify(
     key: jax.Array,
     epsilon: float,
 ) -> jax.Array:
-    """Apply Eq. 10 to a batch x (B, ...). Returns x̃ of the same shape."""
+    """Apply Eq. 10 to a batch x (B, ...). Returns x̃ of the same shape. Its
+    ops (a bank forward and the input gradient) carry the named scope
+    ``ofl.dhs``."""
 
     def scalar(x_in):
         la = logits_all_fn(client_params, x_in)  # (n, B, C)
@@ -37,8 +39,9 @@ def diversify(
         u = jax.random.uniform(key, ens.shape, jnp.float32, -1.0, 1.0)
         return jnp.sum(u * ens)
 
-    g = jax.grad(scalar)(x)
-    flat = g.reshape(g.shape[0], -1).astype(jnp.float32)
-    norm = jnp.linalg.norm(flat, axis=-1)[:, None]
-    direction = (flat / jnp.maximum(norm, 1e-12)).reshape(g.shape)
-    return (x.astype(jnp.float32) + epsilon * direction).astype(x.dtype)
+    with jax.named_scope("ofl.dhs"):
+        g = jax.grad(scalar)(x)
+        flat = g.reshape(g.shape[0], -1).astype(jnp.float32)
+        norm = jnp.linalg.norm(flat, axis=-1)[:, None]
+        direction = (flat / jnp.maximum(norm, 1e-12)).reshape(g.shape)
+        return (x.astype(jnp.float32) + epsilon * direction).astype(x.dtype)

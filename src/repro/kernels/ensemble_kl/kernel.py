@@ -204,6 +204,7 @@ def ensemble_kl_bwd_pallas(
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ensemble_kl_bwd",
     )(
         w.astype(jnp.float32).reshape(k, 1),
         client_logits,
@@ -262,6 +263,7 @@ def ensemble_kl_pallas(
         out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((block_b, 1), jnp.float32) for _ in range(5)],
         interpret=interpret,
+        name="ensemble_kl_fwd",
     )(w.astype(jnp.float32).reshape(k, 1), client_logits, student_logits)
     if return_stats:
         return out[:b, 0], lse_t[:b, 0], lse_s[:b, 0]

@@ -301,6 +301,7 @@ def flash_attention_bwd_pallas(
         out_shape=jax.ShapeDtypeStruct((bhg, sqp, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     # q-minor pass: same operands, grid dims (bh, ki, qi) — swap the maps
@@ -320,6 +321,7 @@ def flash_attention_bwd_pallas(
         out_shape=[jax.ShapeDtypeStruct((bhg, skp, hd), jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((block_kv, hd), jnp.float32)] * 2,
         interpret=interpret,
+        name="flash_attention_dkdv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dq = dq.reshape(b, kh, g, sqp, hd).transpose(0, 3, 1, 2, 4).reshape(b, sqp, h, hd)
@@ -403,6 +405,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     out = res[0].reshape(b, kh, g, sqp, hd).transpose(0, 3, 1, 2, 4).reshape(b, sqp, h, hd)
     if not return_lse:

@@ -163,5 +163,6 @@ def flash_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(page_table.astype(jnp.int32), pos.reshape(-1).astype(jnp.int32), qf, k_pages, v_pages)
     return out.reshape(b, h, hd)

@@ -199,6 +199,7 @@ def ghm_ce_bwd_pallas(
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ghm_ce_bwd",
     )(
         w.astype(jnp.float32).reshape(k, 1),
         client_logits,
@@ -253,6 +254,7 @@ def ghm_ce_pallas(
         out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((block_b, 1), jnp.float32) for _ in range(3)],
         interpret=interpret,
+        name="ghm_ce_fwd",
     )(
         w.astype(jnp.float32).reshape(k, 1),
         client_logits,

@@ -147,15 +147,16 @@ def main() -> None:
     # telemetry (repro.obs) — off by default, zero-cost when off
     p.add_argument("--metrics-out", default=None, metavar="PATH.jsonl",
                    help="dump the ofl.* metrics registry (epoch/phase "
-                        "counters + step-time histograms) as JSONL plus a "
-                        ".prom Prometheus-text sibling at exit")
+                        "counters + the ofl.epoch.step_s histogram, each "
+                        "epoch's execution time, one epoch in flight) as "
+                        "JSONL plus a .prom Prometheus-text sibling at exit")
     p.add_argument("--trace-out", default=None, metavar="PATH.json",
                    help="record host-side phase spans and dump Chrome "
                         "trace-event JSON (Perfetto-loadable) at exit")
     p.add_argument("--profile-dir", default=None,
                    help="also run a JAX profiler trace into this directory "
-                        "(the fused epoch's jax.named_scope phases show up "
-                        "in the device timeline)")
+                        "(the fused epoch's jax.named_scope phases and "
+                        "networks show up in the device timeline)")
     args = p.parse_args()
     enable_compile_cache()
     obs.configure(
@@ -207,7 +208,7 @@ def main() -> None:
         with open(args.out, "w") as f:
             json.dump({"method": args.method, **result}, f, indent=1)
     if args.profile_dir:
-        obs.stop_jax_profile(obs.tracer())
+        jax.profiler.stop_trace()
     if args.metrics_out:
         obs.registry().dump(args.metrics_out)
         log.info("metrics snapshot -> %s (+ .prom)", args.metrics_out)

@@ -146,7 +146,7 @@ def _finalize_telemetry(args, engines=()) -> None:
     for eng in engines:
         eng.publish_gauges()
     if args.profile_dir:
-        obs.stop_jax_profile(obs.tracer())
+        jax.profiler.stop_trace()
     if args.metrics_out:
         obs.registry().dump(args.metrics_out)
         log.info("metrics snapshot -> %s (+ .prom)", args.metrics_out)

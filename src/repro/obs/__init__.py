@@ -9,8 +9,9 @@ Three layers, one import:
   :mod:`repro.obs.names`.
 * **span tracer** (:mod:`repro.obs.tracer`) — ``with obs.span("name"):``
   host-side nested spans into a ring buffer, exported as Perfetto-loadable
-  Chrome trace-event JSON; bridges to ``jax.profiler.TraceAnnotation`` when
-  a profiler trace is active.
+  Chrome trace-event JSON on the profiler's wall clock; every span also
+  enters a ``jax.profiler.TraceAnnotation``, so a profiler trace however
+  started shows it.
 * **per-request timelines** — ``Completion.first_token`` + the TTFT/queue-
   wait percentiles in :mod:`repro.serve.metrics`, dumped alongside the
   registry snapshot by the launchers' ``--metrics-out`` / ``--trace-out``.
@@ -35,7 +36,7 @@ from repro.obs.names import (
     serve_namespace,
 )
 from repro.obs.registry import MetricsRegistry, StatsView
-from repro.obs.tracer import SpanTracer, start_jax_profile, stop_jax_profile
+from repro.obs.tracer import SpanTracer
 
 _registry = MetricsRegistry(enabled=False)
 _tracer = SpanTracer()
@@ -76,8 +77,8 @@ def configure(metrics: bool = False, trace: bool = False,
     """Switch the process-global telemetry on/off (launcher flag plumbing).
 
     ``metrics`` enables the global registry, ``trace`` the span tracer (its
-    ring is cleared so a run's export starts at t=0), and ``profile_dir``
-    starts a JAX profiler trace bridging every span to a TraceAnnotation."""
+    ring is cleared and its clock origin taken anew), and ``profile_dir``
+    starts a JAX profiler trace."""
     global _tracer
     _registry.enabled = metrics
     if trace and _tracer._events.maxlen != trace_capacity:
@@ -86,7 +87,9 @@ def configure(metrics: bool = False, trace: bool = False,
     if trace:
         _tracer.clear()
     if profile_dir:
-        start_jax_profile(_tracer, profile_dir)
+        import jax
+
+        jax.profiler.start_trace(profile_dir)
 
 
 __all__ = [
@@ -108,6 +111,4 @@ __all__ = [
     "observe",
     "inc",
     "configure",
-    "start_jax_profile",
-    "stop_jax_profile",
 ]

@@ -82,15 +82,12 @@ OFL_METRICS = {
     "kd_steps": "ofl.kd.steps",
 }
 
-# phase wall-time histograms (seconds); the fused driver can only time the
-# whole single-dispatch epoch (phases are inside one jitted program — the
-# in-program split shows up in a --profile-dir XLA trace via named_scope)
-OFL_HISTOGRAMS = (
-    "ofl.epoch.step_s",
-    "ofl.gen.step_s",
-    "ofl.ee.step_s",
-    "ofl.kd.step_s",
-)
+# epoch time histogram (seconds): the fused driver's completion-to-completion
+# interval, one epoch in flight. Phases and networks run inside the one
+# jitted program; their split shows up in a --profile-dir XLA trace through
+# the program's named scopes (ofl.gen.boost / ofl.kd, ofl.bank / ofl.gen.net
+# / ofl.server / ofl.dhs).
+OFL_HISTOGRAMS = ("ofl.epoch.step_s",)
 
 #: Metric names a paged continuous-serving smoke run MUST increment — the
 #: drift guard's floor (and repro.obs.validate's required-key set).

@@ -16,10 +16,14 @@ per thread without any explicit parent links. We additionally record the
 enclosing span's name in ``args.parent`` (from a per-thread stack) so tests
 and offline tooling can assert nesting without reconstructing intervals.
 
-When a JAX profiler trace is active (``launch --profile-dir``), every span
-also enters a :class:`jax.profiler.TraceAnnotation` of the same name, so
-the host-side timeline lines up with the XLA device trace in one Perfetto
-view.
+Every span of an enabled tracer also enters a
+:class:`jax.profiler.TraceAnnotation` of the same name, so whichever way a
+JAX profiler trace was started (``launch --profile-dir``,
+``jax.profiler.trace``, an on-demand capture) its host plane holds the
+spans beside the XLA device trace. The exported JSON is on the same clock:
+``ts`` is wall-clock microseconds (``time.time_ns()`` taken at
+:meth:`SpanTracer.clear`, plus ``perf_counter_ns`` since), as the
+profiler's own timestamps are; durations are ``perf_counter_ns``.
 """
 from __future__ import annotations
 
@@ -56,12 +60,11 @@ class _Span:
         self._ann = None
 
     def __enter__(self):
-        tr = self._tracer
-        if tr.jax_bridge:
-            import jax
+        import jax
 
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
+        tr = self._tracer
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         stack = tr._stack()
         if stack:
             self.args.setdefault("parent", stack[-1])
@@ -76,8 +79,7 @@ class _Span:
         if stack and stack[-1] == self.name:
             stack.pop()
         tr._record(self.name, self._t0, t1, self.args)
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -86,10 +88,15 @@ class SpanTracer:
 
     def __init__(self, capacity: int = 65536):
         self.enabled = False
-        self.jax_bridge = False  # set while a jax profiler trace is active
         self._events: Deque[dict] = deque(maxlen=capacity)
         self._local = threading.local()
         self._lock = threading.Lock()
+        self._set_origin()
+
+    def _set_origin(self) -> None:
+        """Pair the wall clock with ``perf_counter_ns``: ``ts`` is the wall
+        clock at the origin plus the ``perf_counter_ns`` elapsed since."""
+        self._origin_unix_ns = time.time_ns()
         self._origin_ns = time.perf_counter_ns()
 
     # -- recording -----------------------------------------------------------
@@ -110,7 +117,7 @@ class SpanTracer:
         ev = {
             "name": name,
             "ph": "X",
-            "ts": (t0_ns - self._origin_ns) / 1e3,  # microseconds
+            "ts": (self._origin_unix_ns + t0_ns - self._origin_ns) / 1e3,  # wall-clock µs
             "dur": (t1_ns - t0_ns) / 1e3,
             "pid": 0,
             "tid": threading.get_ident(),
@@ -132,7 +139,7 @@ class SpanTracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
-        self._origin_ns = time.perf_counter_ns()
+        self._set_origin()
 
     def __len__(self) -> int:
         with self._lock:
@@ -155,7 +162,8 @@ class SpanTracer:
         return {
             "traceEvents": self.events(),
             "displayTimeUnit": "ms",
-            "otherData": {"recorder": "repro.obs.tracer"},
+            "otherData": {"recorder": "repro.obs.tracer", "clock": "unix",
+                          "origin_unix_ns": self._origin_unix_ns},
         }
 
     def dump(self, path: str) -> None:
@@ -167,22 +175,3 @@ def _jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
     return str(v)
-
-
-# -- jax profiler bridge -----------------------------------------------------
-
-
-def start_jax_profile(tracer: SpanTracer, profile_dir: str) -> None:
-    """Start a JAX profiler trace into ``profile_dir`` and bridge every span
-    to a TraceAnnotation so host spans land in the device timeline too."""
-    import jax
-
-    jax.profiler.start_trace(profile_dir)
-    tracer.jax_bridge = True
-
-
-def stop_jax_profile(tracer: SpanTracer) -> None:
-    import jax
-
-    tracer.jax_bridge = False
-    jax.profiler.stop_trace()

@@ -7,7 +7,10 @@
   keys must raise (drift guard);
 * tracer: nested spans nest correctly, Chrome trace JSON round-trips
   ``json.loads`` with per-thread monotonic ``ts``, a disabled tracer records
-  nothing and costs one shared no-op context;
+  nothing and costs one shared no-op context; an enabled one enters a
+  ``TraceAnnotation`` per span, and its ``ts`` is the profiler's wall clock;
+* ``ofl.epoch.step_s``: execution time, one epoch in flight, and no wait
+  when metrics are off;
 * the serving hot path: enabling trace/metrics must not add host syncs to a
   decode chunk (the O(1)-syncs-per-chunk contract), and what a smoke run
   increments must match the namespace ``repro.obs.names`` declares;
@@ -387,3 +390,149 @@ def test_log_level_env_and_set_level(monkeypatch):
             set_level("nope")
     finally:
         root.setLevel(before)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what enters
+    and exits."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+
+        self.cls = Ann
+
+
+def test_spans_reach_trace_annotation_however_the_profile_started(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    tr = SpanTracer()
+    with tr.span("off"):
+        pass
+    assert ann.log == []  # a disabled tracer stays the shared no-op
+    tr.enabled = True
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    assert ann.log == [("enter", "outer"), ("enter", "inner"), ("exit", "inner"), ("exit", "outer")]
+
+
+def test_exported_ts_is_wall_clock_and_monotonic():
+    import threading
+    import time
+
+    tr = SpanTracer()
+    tr.enabled = True
+    tr.clear()
+
+    def work(n):
+        for i in range(n):
+            with tr.span("step", i=i):
+                tr.instant("mark")
+
+    threads = [threading.Thread(target=work, args=(20,)) for _ in range(3)]
+    for t in threads:
+        t.start()
+    work(20)
+    for t in threads:
+        t.join()
+    now_us = time.time_ns() / 1e3
+    doc = tr.to_chrome_trace()
+    assert doc["otherData"]["clock"] == "unix"
+    assert abs(doc["otherData"]["origin_unix_ns"] / 1e3 - now_us) < 1e6
+    evs = doc["traceEvents"]
+    assert len(evs) == 160
+    last = {}
+    for ev in evs:
+        assert abs(ev["ts"] - now_us) < 1e6, ev
+        assert ev["ts"] >= last.get(ev["tid"], float("-inf"))
+        last[ev["tid"]] = ev["ts"]
+
+
+def test_span_start_matches_the_profiler_host_plane(tmp_path):
+    """A span's start in the exported JSON and in a profile's host plane
+    (started with ``jax.profiler.trace``, not through obs) agree within
+    1 ms."""
+    import glob
+    import os
+    import time
+
+    tr = SpanTracer()
+    tr.enabled = True
+    tr.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        for i in range(3):
+            with tr.span("ofl.epoch", epoch=i):
+                time.sleep(0.002)
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    origin = dict(pd.find_plane_with_name("Task Environment").stats)["profile_start_time"]
+    host = sorted(origin + ev.start_ns for plane in pd.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events if ev.name == "ofl.epoch")
+    ours = sorted(ev["ts"] * 1e3 for ev in tr.events() if ev["name"] == "ofl.epoch")
+    assert len(host) == len(ours) == 3
+    assert max(abs(a - b) for a, b in zip(host, ours)) < 1e6
+
+
+# ---------------------------------------------------------------------------
+# ofl.epoch.step_s: execution time, one epoch in flight
+
+
+def _tiny_coboosting(epochs, eval_every):
+    from functools import partial
+
+    from repro.config.train import OFLConfig
+    from repro.core import default_image_setup, run_coboosting
+    from repro.models.cnn import cnn_apply, init_cnn
+
+    classes, shape = 4, (8, 8, 3)
+    cfg = OFLConfig(num_clients=2, epochs=epochs, gen_iters=2, batch_size=4, latent_dim=6, buffer_batches=2)
+    apply = partial(cnn_apply, "mlp")
+    clients = [init_cnn(jax.random.key(i), "mlp", classes, shape) for i in range(2)]
+    server = init_cnn(jax.random.key(9), "mlp", classes, shape)
+    gen_apply, gen = default_image_setup(jax.random.key(5), cfg, classes, shape)
+    eval_fn = (lambda sp, w: {"acc": 0.0}) if eval_every else None
+    return run_coboosting([apply] * 2, clients, apply, server, gen_apply, gen, cfg, classes,
+                          jax.random.key(0), eval_fn=eval_fn, eval_every=eval_every or 1)
+
+
+@pytest.mark.parametrize("eval_every", [None, 1, 2], ids=["no-eval", "eval-each", "eval-every-2"])
+def test_epoch_step_s_times_execution(global_obs_off, tmp_path, eval_every):
+    from repro.obs.validate import main as validate_main
+
+    obs.configure(metrics=True, trace=True)
+    obs.registry().reset()
+    _tiny_coboosting(3, eval_every)
+    snap = {(r["name"], tuple(sorted(r["labels"].items()))): r for r in obs.registry().snapshot()}
+    h = snap[("ofl.epoch.step_s", (("driver", "fused"),))]
+    assert h["type"] == "histogram" and h["count"] == 3 and h["min"] > 0
+    assert not obs.registry().names("ofl.gen.step_s") + obs.registry().names("ofl.ee.step_s") \
+        + obs.registry().names("ofl.kd.step_s")
+    metrics, trace = tmp_path / "m.jsonl", tmp_path / "t.json"
+    obs.registry().dump(str(metrics))
+    obs.tracer().dump(str(trace))
+    assert validate_main(["--train", "--metrics", str(metrics), "--trace", str(trace)]) == 0
+
+
+def test_epoch_step_s_blocks_nothing_when_metrics_are_off(global_obs_off, monkeypatch):
+    from repro.core import coboosting
+
+    waited = []
+    monkeypatch.setattr(coboosting, "_completed", lambda result, since: waited.append(result) or since)
+    obs.configure(metrics=False, trace=True)
+    _tiny_coboosting(3, None)
+    assert waited == []

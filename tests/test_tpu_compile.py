@@ -12,6 +12,11 @@ Shape sets: the paper's image-scale OFL epoch (K=10 clients, b=128, 10
 classes) and smollm-135m (9 query heads, 3 KV heads, head_dim 64, vocab
 49152, 2048-token sequences, 16-token KV pages).
 
+The kernel-name tests lower each kernel for the TPU without a topology and
+without compiling: every ``pallas_call`` carries a stable ``kernel_name``,
+and the name is a component of the kernel's op name, so a device trace
+tells the kernels apart.
+
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and pytest-xdist
 workers all import this file.
@@ -19,6 +24,7 @@ workers all import this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -151,3 +157,92 @@ def test_flash_decode_compiles(one_chip, shape):
         S((b, DECODE_HEADS, HEAD_DIM)), kv, kv, S((b, w), I32), S((b,), I32),
     )
     assert _kernels(text) >= 1
+
+
+# -- kernel names ---------------------------------------------------------------
+
+
+def _lowered_tpu(fn, *args) -> str:
+    """``fn`` lowered for the TPU on the CPU (no chip, no compile), with the
+    op names as locations."""
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def _kernel_names(text: str) -> list:
+    return re.findall(r'kernel_name = "([\w.]+)"', text)
+
+
+def _op_names(text: str) -> set:
+    return set(re.findall(r'^#loc\d+ = loc\("([^"]*)"', text, re.M))
+
+
+def _loss_args(kind):
+    k, b, v, _ = LOSS_SHAPES["ofl-image"]
+    c, w = jnp.ones((k, b, v)), jnp.full((k,), 1.0 / k)
+    if kind == "ensemble_kl":
+        return c, jnp.ones((b, v)), w
+    return c, jnp.zeros((b,), I32), w
+
+
+def _kernel_programs():
+    """Each kernel alone, as its module's entry point calls it."""
+    k, b, v, _ = LOSS_SHAPES["ofl-image"]
+    g = jnp.ones((b,))
+    bq, s, h, kh, hd = 2, 256, 4, 2, 64
+    q, kv = jnp.ones((bq, s, h, hd), BF16), jnp.ones((bq, s, kh, hd), BF16)
+    slots, w = 2, 4
+    pages = jnp.ones((slots * w + 1, PAGE, DECODE_KV_HEADS, HEAD_DIM), BF16)
+    return {
+        "ensemble_kl_fwd": (lambda c, s_, w_: ensemble_kl_pallas(c, s_, w_, 4.0), _loss_args("ensemble_kl")),
+        "ensemble_kl_bwd": (lambda c, s_, w_: ensemble_kl_bwd_pallas(c, s_, w_, g, g, g, g, 4.0), _loss_args("ensemble_kl")),
+        "ghm_ce_fwd": (lambda c, y, w_: ghm_ce_pallas(c, y, w_), _loss_args("ghm_ce")),
+        "ghm_ce_bwd": (lambda c, y, w_: ghm_ce_bwd_pallas(c, y, w_, g, g, g), _loss_args("ghm_ce")),
+        "flash_attention_fwd": (flash_attention_pallas, (q, kv, kv)),
+        "flash_attention_dq": (lambda *a: flash_attention_bwd_pallas(*a)[0],
+                               (q, kv, kv, q, jnp.zeros((bq, s, h)), q)),
+        "flash_attention_dkdv": (lambda *a: flash_attention_bwd_pallas(*a)[1:],
+                                 (q, kv, kv, q, jnp.zeros((bq, s, h)), q)),
+        "flash_decode": (lambda qd, kp, vp, t, n: flash_decode_pallas(qd, kp, vp, t, n),
+                         (jnp.ones((slots, DECODE_HEADS, HEAD_DIM), BF16), pages, pages,
+                          jnp.zeros((slots, w), I32), jnp.full((slots,), 8, I32))),
+    }
+
+
+KERNEL_NAMES = (
+    "ensemble_kl_fwd", "ensemble_kl_bwd", "ghm_ce_fwd", "ghm_ce_bwd",
+    "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkdv", "flash_decode",
+)
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_kernel_is_named(name):
+    """Each ``pallas_call`` carries a stable ``kernel_name``, and the name
+    is a component of the kernel's op name, so a device trace tells the
+    kernels apart."""
+    fn, args = _kernel_programs()[name]
+    text = _lowered_tpu(fn, *args)
+    assert name in _kernel_names(text)
+    assert any(re.search(rf"(^|/){name}/pallas_call$", n) for n in _op_names(text)), name
+
+
+@pytest.mark.parametrize("kind", ["ensemble_kl", "ghm_ce"])
+def test_loss_kernel_names_in_both_passes(kind):
+    """Under ``jax.grad`` the forward kernel's op name holds ``<kind>_fwd``
+    inside the forward pass (``jvp(...)``) and the backward kernel's holds
+    ``<kind>_bwd`` inside the backward pass (``transpose(jvp(...))``)."""
+    from repro.kernels.ensemble_kl.ops import _ensemble_kl_kernel
+    from repro.kernels.ghm_ce.ops import _ghm_ce_kernel
+
+    def loss(c, other, w):
+        with jax.named_scope("loss"):
+            if kind == "ensemble_kl":
+                out = _ensemble_kl_kernel(c, other, w, 4.0, False, 8, 512)
+            else:
+                out = _ghm_ce_kernel(c, other, w, True, True, False, 8, 512)
+            return jnp.mean(out)
+
+    text = _lowered_tpu(jax.grad(loss, argnums=(0, 2)), *_loss_args(kind))
+    assert sorted(_kernel_names(text)) == sorted([f"{kind}_bwd", f"{kind}_fwd"])
+    names = _op_names(text)
+    assert any(n.endswith(f"/jvp(loss)/{kind}_fwd/pallas_call") for n in names), names
+    assert any(n.endswith(f"/transpose(jvp(loss))/{kind}_bwd/pallas_call") for n in names), names
