@@ -113,10 +113,10 @@ def _init_cnn5(key, num_classes, in_shape):
 
 
 def _apply_cnn5(p, x):
-    x = jax.nn.relu(conv2d(x, p["c1"]))
-    x = max_pool(x)
-    x = jax.nn.relu(conv2d(x, p["c2"]))
-    x = max_pool(x)
+    # ReLU after the pool: exact, as ReLU is monotone, and it then runs on the
+    # quarter-size pooled tensor, so no full-resolution activation is kept.
+    x = jax.nn.relu(max_pool(conv2d(x, p["c1"])))
+    x = jax.nn.relu(max_pool(conv2d(x, p["c2"])))
     x = x.reshape(x.shape[0], -1)
     x = jax.nn.relu(x @ p["f1"])
     x = jax.nn.relu(x @ p["f2"])
@@ -138,10 +138,8 @@ def _init_cnn2(key, num_classes, in_shape):
 
 
 def _apply_cnn2(p, x):
-    x = jax.nn.relu(conv2d(x, p["c1"]))
-    x = max_pool(x)
-    x = jax.nn.relu(conv2d(x, p["c2"]))
-    x = max_pool(x)
+    x = jax.nn.relu(max_pool(conv2d(x, p["c1"])))  # after the pool, as in cnn5
+    x = jax.nn.relu(max_pool(conv2d(x, p["c2"])))
     x = x.reshape(x.shape[0], -1)
     x = jax.nn.relu(x @ p["f1"])
     x = jax.nn.relu(x @ p["f2"])

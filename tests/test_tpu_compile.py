@@ -12,6 +12,11 @@ Shape sets: the paper's image-scale OFL epoch (K=10 clients, b=128, 10
 classes) and smollm-135m (9 query heads, 3 KV heads, head_dim 64, vocab
 49152, 2048-token sequences, 16-token KV pages).
 
+The client-bank test compiles the input gradient of the K=10 cnn5 bank at
+b=128 and counts the full-resolution tensors the program writes: ReLU
+after the max-pool leaves the first convolution's output and its pool
+backward, with no ReLU pass or mask at that size.
+
 The kernel-name tests lower each kernel for the TPU without a topology and
 without compiling: every ``pallas_call`` carries a stable ``kernel_name``,
 and the name is a component of the kernel's op name, so a device trace
@@ -23,14 +28,17 @@ workers all import this file.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.client_bank import make_ensemble
 from repro.kernels.ensemble_kl.kernel import ensemble_kl_bwd_pallas, ensemble_kl_pallas
 from repro.kernels.flash_attention.kernel import (
     flash_attention_bwd_pallas,
@@ -38,6 +46,7 @@ from repro.kernels.flash_attention.kernel import (
 )
 from repro.kernels.flash_decode.kernel import flash_decode_pallas
 from repro.kernels.ghm_ce.kernel import ghm_ce_bwd_pallas, ghm_ce_pallas
+from repro.models.cnn import cnn_apply, init_cnn
 
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
@@ -157,6 +166,44 @@ def test_flash_decode_compiles(one_chip, shape):
         S((b, DECODE_HEADS, HEAD_DIM)), kv, kv, S((b, w), I32), S((b,), I32),
     )
     assert _kernels(text) >= 1
+
+
+def _entry_results(text: str):
+    """(opcode, element counts of the result or of each tuple element) for
+    every instruction of the compiled program's entry computation."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", entry, re.M):
+        result, opcode = m.groups()
+        dims = re.findall(r"\w+\[([\d,]*)\]", result)
+        yield opcode, [math.prod(int(d) for d in ds.split(",") if d) for ds in dims]
+
+
+def test_client_bank_grad_writes_two_full_resolution_tensors(one_chip):
+    """The cnn5 bank's input gradient (K=10, b=128, 32x32 images, as in a
+    generator step) writes exactly two tensors of the first convolution's
+    full output size (128 x 32 x 32 x 10*32): the convolution and the pool's
+    select-and-scatter. With ReLU before the pool there were four, the ReLU
+    and its mask besides."""
+    k, b, classes, image = 10, 128, 10, (32, 32, 3)
+    held = {}
+
+    def build():
+        clients = [init_cnn(jax.random.key(i), "cnn5", classes, image) for i in range(k)]
+        held["logits_all"], bank_params = make_ensemble([partial(cnn_apply, "cnn5")] * k, clients)
+        return bank_params
+
+    S = lambda s, d=F32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    params = jax.tree.map(lambda a: S(a.shape, a.dtype), jax.eval_shape(build))
+
+    def input_grad(p, x, ct):
+        return jax.vjp(lambda v: held["logits_all"](p, v), x)[1](ct)[0]
+
+    text = _compile(input_grad, params, S((b, *image)), S((k, b, classes)))
+    full = b * 32 * 32 * k * 32
+    writes = [op for op, sizes in _entry_results(text)
+              if full in sizes and op not in ("bitcast", "get-tuple-element", "parameter")]
+    assert len(writes) == 2 and "select-and-scatter" in writes, writes
 
 
 # -- kernel names ---------------------------------------------------------------
