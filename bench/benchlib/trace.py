@@ -277,14 +277,51 @@ def host_label(trace: dict, t0: float, t1: float) -> str:
     return best
 
 
+TRANSFORMS = ("jvp", "transpose", "vmap", "remat", "checkpoint")
+STRUCTURAL = re.compile(r"^(|while|body|cond|closed_call|checkpoint|remat|scan|branch_\d+_fun|p?jit\(.*\))$")
+
+
+def _unwrap(component: str) -> Tuple[List[str], str]:
+    """``transpose(jvp(ofl.bank.g0))`` -> (["transpose", "jvp"], "ofl.bank.g0")."""
+    wraps = []
+    while True:
+        m = re.match(r"^(\w+)\((.*)\)$", component)
+        if not m or m.group(1) not in TRANSFORMS:
+            return wraps, component
+        wraps.append(m.group(1))
+        component = m.group(2)
+
+
 def op_label(dev: dict, i: int) -> str:
-    """Group key for the breakdown: the op's scope path, else its kind
-    (the instruction name without its number)."""
-    scope = dev["scopes"][i]
-    if scope:
-        return re.sub(r"\.\d+", "", scope)[-120:]
-    inst = instruction(dev["names"][i])
-    return re.sub(r"\.\d+$", "", inst)[:120]
+    """Group key for the breakdown, most telling first: the innermost named
+    scope of the op's scope path, the transforms it runs under (outermost
+    first), the op's kind, then the outer named scopes, e.g.
+    ``ofl.bank.g0 transpose(jvp) convolution / ofl.gen.boost``. The kind is
+    the instruction name without its number, or for a bare ``fusion`` the
+    primitive it was built from. Control flow and ``jit(...)`` are not named
+    scopes; an op under none leads with its program, and one with no scope
+    path is its kind alone."""
+    kind = re.sub(r"\.\d+$", "", instruction(dev["names"][i]))
+    scope = dev["scopes"][i].split(";")[0]
+    if not scope:
+        return kind[:120]
+    path = scope.split("/")
+    if kind == "fusion":
+        kind = path[-1]  # a bare fusion is named by the primitive it was built from
+    parts = [_unwrap(c) for c in path[:-1]]
+    named = [k for k, (_, name) in enumerate(parts) if not STRUCTURAL.match(name)]
+    if not named:
+        named = [k for k, (_, name) in enumerate(parts) if name.startswith(("jit(", "pjit("))][:1]
+    if not named:
+        return kind[:120]
+    k = named[-1]
+    wraps = [w for ws, _ in parts for w in ws]
+    label = [parts[k][1]]
+    if wraps:
+        label.append("(".join(wraps) + ")" * (len(wraps) - 1))
+    label.append(kind)
+    outer = "/".join(parts[j][1] for j in named[:-1])
+    return (" ".join(label) + (f" / {outer}" if outer else ""))[:120]
 
 
 CONTAINERS = re.compile(r"\s(while|conditional|call)\(")

@@ -83,9 +83,9 @@ def run(args, *, cpu_peaks: dict | None = None) -> dict:
     else:
         devices = common.need_chips(jax, work["chips"])
     common.enable_cache(jax)
-    from benchlib import ofl, trace
+    from benchlib import ofl, serve, trace
 
-    kind = {"ofl": ofl}[work["driver"]]
+    kind = {"ofl": ofl, "serve": serve}[work["driver"]]
     ctx = {
         "jax": jax, "workload": work, "config": cfg, "traffic": traffic, "name": args.workload,
         "seed": args.seed, "seconds": args.seconds, "trace": bool(args.trace), "devices": devices,

@@ -79,12 +79,28 @@ def test_breakdown_by_hand():
     tr.attach_scopes(t, tr.hlo_scopes(HLO), "epoch_step")
     b = tr.breakdown(t)
     names = [n for n, _ in b["device_ops"]]
-    assert names[0] == "jit(epoch_step)/ofl.kd/scan/body/dot" and len(names) == 5
+    assert names[0] == "ofl.kd dot" and len(names) == 5
     assert "copy" in names  # an op with no scope is grouped by its kind
     assert sum(s for _, s in b["device_ops"]) == pytest.approx(55e-9)
     assert b["idle_gaps"][0] == ["bench.wait", pytest.approx(20e-9)]
     assert sorted(name for name, _ in b["idle_gaps"][1:]) == ["bench.dispatch", "no host span"]
     assert len(b["idle_gaps"]) == 3
+
+
+@pytest.mark.parametrize("scope, name, label", [
+    ("jit(epoch_step)/ofl.gen.boost/while/body/closed_call/transpose(jvp(ofl.bank.g0))/conv_general_dilated",
+     "%convolution.12 = f32[8]{0} convolution(f32[8]{0} %p)", "ofl.bank.g0 transpose(jvp) convolution / ofl.gen.boost"),
+    ("jit(epoch_step)/ofl.gen.boost/while/body/closed_call/ofl.bank/jvp()/ofl.bank.g0/reduce_window_max",
+     "%fusion.7 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop", "ofl.bank.g0 jvp reduce_window_max / ofl.gen.boost/ofl.bank"),
+    ("jit(epoch_step)/ofl.kd/while/body/closed_call/jvp(jit(ghm_ce))/pallas_call",
+     '%ghm_ce_fwd.3 = f32[8]{0} custom-call(f32[8]{0} %p), custom_call_target="tpu_custom_call"', "ofl.kd jvp ghm_ce_fwd"),
+    ("jit(_chunk_fn)/while/body/dot_general", "%fusion.40 = f32[8]{0} fusion(f32[8]{0} %p), kind=kOutput",
+     "jit(_chunk_fn) dot_general"),
+    ("", "%copy-start.2 = f32[8]{0} copy-start(f32[8]{0} %p)", "copy-start"),
+])
+def test_op_label_leads_with_the_innermost_scope_and_the_kind(scope, name, label):
+    dev = {"names": [name], "scopes": [scope]}
+    assert tr.op_label(dev, 0) == label
 
 
 @pytest.mark.parametrize("path", RECORDED, ids=[p.name for p in RECORDED])
