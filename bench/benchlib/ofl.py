@@ -181,7 +181,8 @@ def run_cell(ctx: dict) -> dict:
     with tr.profiled(jax, ctx["trace"]) as prof:
         with jax.profiler.TraceAnnotation("bench.window"):
             t0 = time.perf_counter()
-            n, prev = 0, None
+            n, prev, step_end = 0, None, t0
+            longest = (0.0, 0.0)  # the longest epoch the host saw, and when it ended
             while True:
                 with jax.profiler.TraceAnnotation("bench.dispatch"):
                     _, dmean = prog.step()
@@ -190,7 +191,10 @@ def run_cell(ctx: dict) -> dict:
                     with jax.profiler.TraceAnnotation("bench.wait"):
                         prev.block_until_ready()
                 prev = dmean
-                if time.perf_counter() - t0 >= ctx["seconds"]:
+                now = time.perf_counter()
+                longest = max(longest, (now - step_end, now - t0))
+                step_end = now
+                if now - t0 >= ctx["seconds"]:
                     break
             with jax.profiler.TraceAnnotation("bench.wait"):
                 last = float(prev)
@@ -217,5 +221,6 @@ def run_cell(ctx: dict) -> dict:
         "memory_peak_bytes": mem,
         "checks": checks,
         "notes": {"compiles_in_window": c1[0] - c0[0], "window_s": window, "epochs": n,
+                  "longest_host_step_s": longest[0], "longest_host_step_at_s": longest[1],
                   "ref_gen_loss": r["gen_loss"], "prog_gen_loss": got["gen_loss"]},
     }

@@ -77,22 +77,59 @@ class WindowClock:
         self._last = time.monotonic() - self._t0
 
 
+# -- the engine's private state ------------------------------------------------
+# Every read the driver makes of state the program keeps private, in one place,
+# so that a change to the engine breaks here and nowhere else. Each goes once
+# the program gives it a public accessor (PERF.md, section 7).
+
+
+def launcher_engine_config(launch, args):
+    """The serving launcher's EngineConfig for its parsed arguments."""
+    return launch._continuous_engine_config(args)
+
+
+def page_table(eng):
+    """The decode worker's page table on the device."""
+    return eng.decode._state.page_table
+
+
+def resident_slots(eng) -> dict:
+    """The decode worker's resident slots: slot -> (prompt length, budget)."""
+    return eng.decode._meta
+
+
+def chunk_hlo(eng) -> str:
+    """The HLO text of the compiled decode chunk program."""
+    d = eng.decode
+    return d._chunk_jit.lower(d.params, d._state).compile().as_text()
+
+
 def model_config(cfg: dict):
     """The program's ModelConfig for the configuration file: the registry's
-    entry with every published size, the RMSNorm epsilon and the precision
-    taken from the file."""
-    from repro.config import get_arch
+    entry named by ``arch``, then the published Llama-style sizes, the
+    RMSNorm epsilon and the precision from the file (``head_dim`` where the
+    file gives one, else ``hidden_size / num_attention_heads``), then the
+    file's ``program`` object, a map of ModelConfig field to value, for
+    whatever else the model states (experts held, latent widths, per-layer
+    attention kinds, multipliers). A name there that is not a ModelConfig
+    field is an error."""
+    from repro.config import ModelConfig, get_arch
     from repro.kernels import BackendPolicy
 
+    program = cfg.get("program", {})
+    unknown = sorted(set(program) - {f.name for f in dataclasses.fields(ModelConfig)})
+    if unknown:
+        raise ValueError(f"configuration {cfg['name']!r}: 'program' names {unknown}, which are not ModelConfig fields")
     return get_arch(cfg["arch"]).replace(
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
         num_heads=cfg["num_attention_heads"], num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"], d_ff=cfg["intermediate_size"],
+        head_dim=cfg.get("head_dim", cfg["hidden_size"] // cfg["num_attention_heads"]),
+        d_ff=cfg["intermediate_size"],
         vocab_size=cfg["vocab_size"], tie_embeddings=cfg["tie_word_embeddings"],
         rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"], act=cfg["hidden_act"],
         dtype=cfg["dtype"], param_dtype=cfg["param_dtype"],
         backend=BackendPolicy(default=cfg["backend"]),
-    )
+    ).replace(**program)
 
 
 def engine_config(mcfg, cfg: dict, seed: int):
@@ -109,7 +146,7 @@ def engine_config(mcfg, cfg: dict, seed: int):
     ] + (["--prefix-cache"] if e["prefix_cache"] else []) + (["--spec-decode"] if e["spec_decode"] else [])
     args = launch.build_parser().parse_args(argv)
     launch.validate_args(args, mcfg)
-    ecfg = dataclasses.replace(launch._continuous_engine_config(args), prefill_bucket=e["prefill_bucket"])
+    ecfg = dataclasses.replace(launcher_engine_config(launch, args), prefill_bucket=e["prefill_bucket"])
     if ecfg.max_seq != e["max_seq"]:
         raise ValueError(f"the launcher makes max_seq {ecfg.max_seq}, the configuration states {e['max_seq']}")
     return ecfg
@@ -157,7 +194,7 @@ class Program:
                 n *= 2
         t1 = time.perf_counter()
         sizes = table_update_sizes(e.max_slots, eng.pool.pages_per_slot)
-        table = eng.decode._state.page_table
+        table = page_table(eng)
         jnp = self.jax.numpy
         for n in sizes:
             idx = [0] * n
@@ -186,10 +223,6 @@ class Program:
         except Deadline:
             comps = []
         return len(reqs) - len(valid(comps, {r.rid: r for r in reqs}, self.mcfg.vocab_size))
-
-    def chunk_hlo(self) -> str:
-        d = self.engine.decode
-        return d._chunk_jit.lower(d.params, d._state).compile().as_text()
 
     def free(self) -> None:
         self.engine = self.sched = None
@@ -282,7 +315,7 @@ def traced_engine(jax, eng, log: dict) -> None:
     def sync():
         active, n_out = real_sync()
         steps = {}
-        for slot, (true_len, _) in eng.decode._meta.items():
+        for slot, (true_len, _) in resident_slots(eng).items():
             n0, n1 = prev.get(slot, 1), int(n_out[slot])
             for j, n in enumerate(range(n0, n1)):
                 ctx, rows = steps.get(j, (0, 0))
@@ -339,7 +372,7 @@ def run_cell(ctx: dict) -> dict:
     c1 = clock.snapshot()
     mem = common.peak_memory(ctx["devices"])
     if ctx["trace"]:
-        tr.attach_scopes(prof.trace, tr.hlo_scopes(prog.chunk_hlo()), CHUNK_PROGRAM)
+        tr.attach_scopes(prof.trace, tr.hlo_scopes(chunk_hlo(prog.engine)), CHUNK_PROGRAM)
     prog.free()
 
     good = valid(comps, by_rid, cfg["vocab_size"])

@@ -151,6 +151,49 @@ def test_reference_weights_fit_the_program_s_tree():
     assert shape(ours) == shape(theirs)
 
 
+def _llama_mapping(cfg):
+    """The ModelConfig the serve driver built before configurations could
+    state program fields: the registry entry, then the Llama keys."""
+    from repro.config import get_arch
+    from repro.kernels import BackendPolicy
+
+    return get_arch(cfg["arch"]).replace(
+        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        num_heads=cfg["num_attention_heads"], num_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"], d_ff=cfg["intermediate_size"],
+        vocab_size=cfg["vocab_size"], tie_embeddings=cfg["tie_word_embeddings"],
+        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"], act=cfg["hidden_act"],
+        dtype=cfg["dtype"], param_dtype=cfg["param_dtype"],
+        backend=BackendPolicy(default=cfg["backend"]),
+    )
+
+
+def test_smollm_builds_the_same_model_config_as_before():
+    import dataclasses
+
+    cfg = common.load_json("configs", "serve-smollm-135m")
+    got, want = serve.model_config(cfg), _llama_mapping(cfg)
+    for f in dataclasses.fields(got):
+        assert getattr(got, f.name) == getattr(want, f.name), f.name
+    assert got == want and got.head_dim == 64
+
+
+def test_program_object_sets_any_model_config_field_and_refuses_other_names():
+    import dataclasses
+
+    cfg = dict(common.load_json("configs", "serve-smollm-135m"), head_dim=80,
+               program={"qk_norm": True, "num_experts": 8, "experts_per_token": 2, "sliding_window": 512})
+    m = serve.model_config(cfg)
+    assert m.head_dim == 80 and m.qk_norm and (m.num_experts, m.experts_per_token, m.sliding_window) == (8, 2, 512)
+    base = serve.model_config(common.load_json("configs", "serve-smollm-135m"))
+    for f in dataclasses.fields(base):
+        value = getattr(base, f.name)
+        stated = dict(cfg, program={f.name: value})
+        assert getattr(serve.model_config(stated), f.name) == value, f.name
+    with pytest.raises(ValueError, match="not_a_field"):
+        serve.model_config(dict(cfg, program={"num_layers": 2, "not_a_field": 1}))
+
+
 # -- whole runs ----------------------------------------------------------------
 
 
@@ -162,6 +205,37 @@ def test_sound_run_is_correct_and_prints_every_check(small):
     assert res["metrics"]["serve_norm_latency_ms"]["value"] > 0 and res["metrics"]["setup_s"]["value"] > 0
     assert 0 < res["metrics"]["serve_tpot_ms"]["value"] <= res["metrics"]["serve_norm_latency_ms"]["value"]
     assert res["attempted"] > 0 and list(res)[-1] == "checks"
+
+
+def test_nothing_compiles_in_the_window(small, monkeypatch):
+    """Set-up warms every program the window reaches: the run's compile
+    clock counts no compile between the window's start and its close. The
+    offer outruns the 4 slots, so requests queue and are admitted in bursts
+    of several, as they are at the cell's rate on the chip."""
+    import jax
+
+    def crowded(kind, name):
+        d = tiny(kind, name)
+        if kind == "traffic":
+            d["rate"] = 30.0
+        if kind == "workloads":
+            d["tail_s"] = 60
+        return d
+
+    monkeypatch.setattr(common, "load_json", crowded)
+    notes = {}
+    real = serve.run_cell
+
+    def keep(ctx):
+        out = real(ctx)
+        notes.update(out["notes"])
+        return out
+
+    monkeypatch.setattr(serve, "run_cell", keep)
+    jax.clear_caches()
+    res = run_cell(seed=2**31 + 131, seconds=3.0)
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] > 40, res["checks"]
+    assert notes["compiles_in_window"] == 0, notes
 
 
 def test_traced_run_on_the_cpu_reads_the_scheduler(small):
